@@ -166,7 +166,6 @@ def measure_ar_duration(mesh, n_elems, dp):
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     x = jnp.zeros((n_elems + (-n_elems) % dp,), jnp.float32)
@@ -175,10 +174,10 @@ def measure_ar_duration(mesh, n_elems, dp):
         red = lax.psum_scatter(v, "data", scatter_dimension=0, tiled=True)
         return lax.all_gather(red, "data", axis=0, tiled=True)
 
-    # RS+AG leaves the value replicated over data, but the rep checker
+    # RS+AG leaves the value replicated over data, but the vma checker
     # can't infer that through psum_scatter — disable it
-    f = jax.jit(shard_map(rs_ag, mesh=mesh, in_specs=P(), out_specs=P(),
-                          check_rep=False))
+    f = jax.jit(jax.shard_map(rs_ag, mesh=mesh, in_specs=P(), out_specs=P(),
+                              check_vma=False))
     return _time(f, x)
 
 

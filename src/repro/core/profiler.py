@@ -435,14 +435,11 @@ def measure_w_frac(cfg: ArchConfig, seq: int = 128, iters: int = 5,
     weight-gradient share.
 
     Returns ``w_frac`` in (0, 1), or ``None`` when timing is
-    unavailable or degenerate (no jax, ``kind="moe"`` without an MoE
-    config, ``kind="ssm"`` without an SSM config, or noise pushes the
-    ratio out of (0.02, 0.98)) — callers fall back to the per-layer
-    analytic split."""
-    try:
-        import jax  # noqa: F401
-    except Exception:
-        return None
+    unavailable or degenerate (``kind="moe"`` without an MoE config,
+    ``kind="ssm"`` without an SSM config, or noise pushes the ratio out
+    of (0.02, 0.98)) — callers fall back to the per-layer analytic
+    split.  A failure to compile or run the proxy raises."""
+    import jax
     if kind not in ("dense", "moe", "ssm"):
         raise ValueError(f"kind must be 'dense', 'moe' or 'ssm', "
                          f"got {kind!r}")
@@ -450,27 +447,22 @@ def measure_w_frac(cfg: ArchConfig, seq: int = 128, iters: int = 5,
         return None
     if kind == "ssm" and cfg.ssm is None:
         return None
-    try:
-        import jax
+    p0, x, ct, block = _block_proxy(cfg, seq, kind)
 
-        p0, x, ct, block = _block_proxy(cfg, seq, kind)
+    def vjp_full(p, x, ct):
+        return jax.vjp(block, p, x)[1](ct)
 
-        def vjp_full(p, x, ct):
-            return jax.vjp(block, p, x)[1](ct)
+    def vjp_input_only(x, ct):
+        return jax.vjp(lambda xx: block(p0, xx), x)[1](ct)
 
-        def vjp_input_only(x, ct):
-            return jax.vjp(lambda xx: block(p0, xx), x)[1](ct)
-
-        t_full = measure_layer(vjp_full, p0, x, ct, iters=iters)
-        t_x = measure_layer(vjp_input_only, x, ct, iters=iters)
-        if t_full <= 0:
-            return None
-        wf = (t_full - t_x) / t_full
-        if not 0.02 < wf < 0.98:
-            return None
-        return wf
-    except Exception:
+    t_full = measure_layer(vjp_full, p0, x, ct, iters=iters)
+    t_x = measure_layer(vjp_input_only, x, ct, iters=iters)
+    if t_full <= 0:
         return None
+    wf = (t_full - t_x) / t_full
+    if not 0.02 < wf < 0.98:
+        return None
+    return wf
 
 
 def _block_proxy(cfg: ArchConfig, seq: int, kind: str):
@@ -557,12 +549,10 @@ def measure_block_time(cfg: ArchConfig, seq: int = 64, iters: int = 3,
     """Median wall-time of ONE full vjp (forward + both cotangents)
     through the reduced block proxy of ``kind`` — the live-timing
     primitive behind :func:`measure_stage_times`.  Returns ``None``
-    when timing is unavailable (no jax, ``kind`` has no matching
-    config) — callers fall back to the analytic cost vector."""
-    try:
-        import jax  # noqa: F401
-    except Exception:
-        return None
+    when timing is unavailable (``kind`` has no matching config, or the
+    clock reads no time) — callers fall back to the analytic cost
+    vector.  A failure to compile or run the proxy raises."""
+    import jax
     if kind not in ("dense", "moe", "ssm"):
         raise ValueError(f"kind must be 'dense', 'moe' or 'ssm', "
                          f"got {kind!r}")
@@ -570,18 +560,13 @@ def measure_block_time(cfg: ArchConfig, seq: int = 64, iters: int = 3,
         return None
     if kind == "ssm" and cfg.ssm is None:
         return None
-    try:
-        import jax
+    p0, x, ct, block = _block_proxy(cfg, seq, kind)
 
-        p0, x, ct, block = _block_proxy(cfg, seq, kind)
+    def vjp_full(p, x, ct):
+        return jax.vjp(block, p, x)[1](ct)
 
-        def vjp_full(p, x, ct):
-            return jax.vjp(block, p, x)[1](ct)
-
-        t = measure_layer(vjp_full, p0, x, ct, iters=iters)
-        return t if t > 0 else None
-    except Exception:
-        return None
+    t = measure_layer(vjp_full, p0, x, ct, iters=iters)
+    return t if t > 0 else None
 
 
 def stage_layer_kinds(cfg: ArchConfig, plan) -> list[list[str]]:

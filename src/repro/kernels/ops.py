@@ -1,9 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``use_pallas`` flows from model configs; on this CPU container kernels run
-in interpret mode (the TPU lowering is exercised on real hardware).  The
-wrappers adapt the model-layer layouts ([B,T,H,D] GQA attention, SSD block
-tensors) to the kernels' flattened layouts.
+Callers choose ``interpret`` explicitly: ``True`` runs the Pallas
+interpreter (the CPU path of the tests), ``False`` lowers to Mosaic for
+the TPU.  The wrappers adapt the model-layer layouts ([B,T,H,D] GQA
+attention, SSD block tensors) to the kernels' flattened layouts.
 """
 from __future__ import annotations
 
@@ -18,7 +18,7 @@ from repro.kernels.ssd_scan import ssd_scan
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "interpret"))
 def mha_flash(q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float,
-              causal: bool = True, interpret: bool = True) -> jax.Array:
+              causal: bool = True, interpret: bool) -> jax.Array:
     """[B,T,Hq,D] x [B,S,Hkv,D] GQA flash attention (kv broadcast to q
     heads, batch*heads flattened for the kernel)."""
     B, T, Hq, D = q.shape
@@ -36,6 +36,5 @@ def mha_flash(q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
-        Cm: jax.Array, *, chunk: int = 256,
-        interpret: bool = True) -> jax.Array:
+        Cm: jax.Array, *, chunk: int = 256, interpret: bool) -> jax.Array:
     return ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)

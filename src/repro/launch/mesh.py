@@ -11,17 +11,15 @@ device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: newer jax wants explicit Auto
-    axis types for shard_map meshes, older jax has no ``axis_types``."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(shape))
-    except (ImportError, AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes (the shard_map runtime's mesh
+    type; jax defaults to Explicit).  ``devices`` defaults to the first
+    ``prod(shape)`` of ``jax.devices()``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -27,21 +27,33 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs import get_config
+from repro.configs import resolve_config
 from repro.pipeline import runtime as RT
 from repro.pipeline import stage as ST
 
 
-def main(argv=None):
+class Served(NamedTuple):
+    prompt: np.ndarray           # [batch, prompt_len] token ids
+    tokens: np.ndarray           # [batch, gen] greedy tokens
+    prefill_logits: np.ndarray   # [batch, vocab] at the last prompt position
+
+
+def main(argv=None) -> Served:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--reduced", action="store_true",
+                    help="smoke-test widths (d_model 256, <= 4 heads, "
+                         "vocab <= 1024)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="depth; without --reduced every width stays as "
+                         "published")
     ap.add_argument("--data", type=int, default=1)
     ap.add_argument("--stages", type=int, default=0)
     ap.add_argument("--tensor", type=int, default=0)
@@ -58,9 +70,11 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced(n_layers=4, d_model=256)
+    try:
+        cfg = resolve_config(args.arch, reduced=args.reduced,
+                             layers=args.layers)
+    except ValueError as e:
+        ap.error(str(e))
     if args.stages:
         cfg = dataclasses.replace(cfg, stages=args.stages)
     if args.tensor:
@@ -157,6 +171,7 @@ def main(argv=None):
         params = params_p
     t_handoff = time.perf_counter() - t0
 
+    prefill_logits = np.asarray(logits[:, 0, :cfg.vocab])
     next_tok = jnp.argmax(logits[:, 0, :cfg.vocab], axis=-1).astype(jnp.int32)
     generated = [np.asarray(next_tok)]
 
@@ -185,8 +200,10 @@ def main(argv=None):
     print("sample generations (first 3 rows):")
     for row in toks[:3]:
         print("  ", row.tolist())
-    return toks
+    return Served(np.asarray(prompt), toks, prefill_logits)
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -23,7 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import (checkpoint_meta, layout_dict,
                               reshard_checkpoint, restore_checkpoint,
                               save_checkpoint)
-from repro.configs import get_config
+from repro.configs import resolve_config
 from repro.data import SyntheticLM
 from repro.data.synthetic import shard_batch
 from repro.optim import AdamW, warmup_cosine
@@ -34,9 +34,14 @@ from repro.pipeline import stage as ST
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--layers", type=int, default=0)
-    ap.add_argument("--d-model", type=int, default=0)
+    ap.add_argument("--reduced", action="store_true",
+                    help="smoke-test widths (d_model 256, <= 4 heads, "
+                         "vocab <= 1024)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="depth; without --reduced every width stays as "
+                         "published")
+    ap.add_argument("--d-model", type=int, default=0,
+                    help="with --reduced: the reduced d_model")
     ap.add_argument("--data", type=int, default=1)
     ap.add_argument("--stages", type=int, default=0)
     ap.add_argument("--tensor", type=int, default=0)
@@ -138,10 +143,12 @@ def main(argv=None):
                          "per-device cost vector")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced(n_layers=args.layers or 4,
-                          d_model=args.d_model or 256, seq=args.seq)
+    try:
+        cfg = resolve_config(args.arch, reduced=args.reduced,
+                             layers=args.layers, d_model=args.d_model,
+                             seq=args.seq)
+    except ValueError as e:
+        ap.error(str(e))
     if args.stages:
         cfg = dataclasses.replace(cfg, stages=args.stages)
     if args.tensor:
@@ -203,9 +210,11 @@ def main(argv=None):
               f"V={plan_.virtual} "
               f"(predicted {plan_.predicted_step_time*1e3:.2f} ms/step)")
     need = args.data * cfg.stages * cfg.tensor
-    assert need <= jax.device_count(), \
-        f"mesh needs {need} devices, have {jax.device_count()} " \
-        "(set XLA_FLAGS=--xla_force_host_platform_device_count=N)"
+    if need > jax.device_count():
+        ap.error(f"mesh needs {need} devices, have {jax.device_count()} "
+                 f"{jax.devices()[0].platform} devices (CPU: set XLA_FLAGS="
+                 f"--xla_force_host_platform_device_count={need}; TPU: run "
+                 f"on a host with {need} chips)")
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((args.data, cfg.stages, cfg.tensor),
                      ("data", "stage", "tensor"))
@@ -237,6 +246,9 @@ def main(argv=None):
                         step=step_done,
                         extra=dict(layout=layout, arch=cfg.arch_id))
 
+    # the state lives on the mesh in the step's own layout from the start,
+    # so step 1 does not compile again for differently placed inputs
+    p_sh, o_sh = RT.state_shardings(mesh, specs, opt_state)
     start_step = 0
     if args.resume:
         meta = checkpoint_meta(args.resume)
@@ -252,13 +264,14 @@ def main(argv=None):
                   f"{src_layout['stages']} x virtual"
                   f"{src_layout.get('virtual', 1)} -> stages"
                   f"{plan.n_stages} x virtual{plan.virtual}")
-        p_sh, o_sh = RT.state_shardings(mesh, specs, opt_state)
         state = restore_checkpoint(resume_path,
                                    dict(params=params, opt=opt_state),
                                    shardings=dict(params=p_sh, opt=o_sh))
         params, opt_state = state["params"], state["opt"]
         start_step = int(checkpoint_meta(resume_path)["step"])
         print(f"resumed from {resume_path} at step {start_step}")
+    else:
+        params, opt_state = jax.device_put((params, opt_state), (p_sh, o_sh))
 
     monitor, inject, replanned = None, None, False
     if args.drift_every:
@@ -282,8 +295,7 @@ def main(argv=None):
             with open(args.losses_out, "w") as f:
                 json.dump(dict(start=start_step, losses=losses), f)
 
-    t0 = time.time()
-    losses = []
+    losses, step_s = [], []
     for step in range(start_step, args.steps):
         batch = shard_batch(data.batch(step), bspec)
         if cfg.family == "audio":
@@ -292,13 +304,13 @@ def main(argv=None):
             batch["pos3"] = jnp.broadcast_to(
                 jnp.arange(args.seq)[None, None],
                 (3, args.batch, args.seq)).astype(jnp.int32)
+        t0 = time.perf_counter()
         params, opt_state, metrics = step_fn(params, opt_state, batch)
-        losses.append(float(metrics["loss"]))
+        losses.append(float(metrics["loss"]))   # waits for the step
+        step_s.append(time.perf_counter() - t0)
         if step % args.log_every == 0 or step == args.steps - 1:
-            dt = time.time() - t0
-            tput = (step + 1 - start_step) * args.batch * args.seq / dt
             print(f"step {step:5d} loss {losses[-1]:.4f} "
-                  f"({tput:.0f} tok/s)", flush=True)
+                  f"({step_s[-1]:.4f} s)", flush=True)
         if monitor is not None and not replanned \
                 and (step + 1) % args.drift_every == 0:
             from repro.core import profiler as PF
@@ -344,6 +356,12 @@ def main(argv=None):
     n = min(10, max(1, len(losses)))
     print(f"first-{n} mean loss {sum(losses[:n])/n:.4f} -> "
           f"last-{n} mean loss {sum(losses[-n:])/n:.4f}")
+    if len(step_s) > 1:
+        # the first step compiles; the rest are steady state
+        steady = sorted(step_s[1:])[len(step_s[1:]) // 2]
+        print(f"timing: first step {step_s[0]:.3f} s (compile included), "
+              f"steady step median {steady:.4f} s over {len(step_s) - 1} "
+              f"steps ({args.batch * args.seq / steady:.0f} tok/s)")
     _dump_losses()
     if args.ckpt:
         _save(args.ckpt, args.steps, params, opt_state)
@@ -352,4 +370,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

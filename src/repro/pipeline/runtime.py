@@ -80,7 +80,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ArchConfig
 from repro.core import schedplan as SP
@@ -900,10 +899,10 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
     def fn(params, batch):
         keys = tuple(sorted(batch))
         if keys not in _built:
-            _built[keys] = shard_map(
+            _built[keys] = jax.shard_map(
                 sharded_step, mesh=mesh,
                 in_specs=(specs, batch_spec_for(keys)),
-                out_specs=(P(), specs), check_rep=False)
+                out_specs=(P(), specs), check_vma=False)
         return _built[keys](params, batch)
 
     if optimizer is None:
@@ -1296,10 +1295,10 @@ def make_serve_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
                 f"state that padding tokens would pollute — serve it "
                 f"with uniform steps instead")
         if keys not in _built:
-            _built[keys] = shard_map(
+            _built[keys] = jax.shard_map(
                 sharded_decode, mesh=mesh,
                 in_specs=(specs, cspecs, batch_spec_for(keys)),
-                out_specs=out_specs, check_rep=False)
+                out_specs=out_specs, check_vma=False)
         return _built[keys](params, cache, batch)
 
     return jax.jit(fn, donate_argnums=(1,)), specs, cspecs, cache_shapes

@@ -158,10 +158,10 @@ def serve_driver(arch):
     base = ["--arch", arch, "--reduced", "--data", "2", "--stages", "2",
             "--tensor", "2", "--batch", "8", "--prompt-len", "16",
             "--gen", "8"]
-    toks = serve_main(base)
+    toks = serve_main(base).tokens
     assert toks.shape == (8, 8)
     # interleaved prefill + donated restack handoff must not change tokens
-    toks_v2 = serve_main(base + ["--virtual", "2"])
+    toks_v2 = serve_main(base + ["--virtual", "2"]).tokens
     assert (toks_v2 == toks).all()
     print("OK")
 
