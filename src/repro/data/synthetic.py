@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core import trace
+
 
 @dataclasses.dataclass
 class SyntheticLM:
@@ -32,18 +34,20 @@ class SyntheticLM:
                                   dtype=np.int64)
 
     def batch(self, step: int) -> dict:
-        rng = np.random.default_rng((self.seed, step))
-        B, T = self.global_batch, self.seq_len + 1
-        toks = np.empty((B, T), np.int64)
-        toks[:, 0] = rng.integers(0, self.vocab, size=B)
-        branch = rng.integers(0, self.branch, size=(B, T))
-        noise_mask = rng.random((B, T)) < self.noise
-        noise_tok = rng.integers(0, self.vocab, size=(B, T))
-        for t in range(1, T):
-            nxt = self.table[toks[:, t - 1], branch[:, t]]
-            toks[:, t] = np.where(noise_mask[:, t], noise_tok[:, t], nxt)
-        return dict(tokens=jnp.asarray(toks[:, :-1], jnp.int32),
-                    labels=jnp.asarray(toks[:, 1:], jnp.int32))
+        with trace.span(trace.DATA_BUILD):
+            rng = np.random.default_rng((self.seed, step))
+            B, T = self.global_batch, self.seq_len + 1
+            toks = np.empty((B, T), np.int64)
+            toks[:, 0] = rng.integers(0, self.vocab, size=B)
+            branch = rng.integers(0, self.branch, size=(B, T))
+            noise_mask = rng.random((B, T)) < self.noise
+            noise_tok = rng.integers(0, self.vocab, size=(B, T))
+            for t in range(1, T):
+                nxt = self.table[toks[:, t - 1], branch[:, t]]
+                toks[:, t] = np.where(noise_mask[:, t], noise_tok[:, t], nxt)
+        with trace.span(trace.DATA_PUT):
+            return dict(tokens=jnp.asarray(toks[:, :-1], jnp.int32),
+                        labels=jnp.asarray(toks[:, 1:], jnp.int32))
 
 
 def make_batch_specs(mesh, batch_axes=("data",)):
@@ -52,5 +56,6 @@ def make_batch_specs(mesh, batch_axes=("data",)):
 
 
 def shard_batch(batch: dict, shardings: dict) -> dict:
-    return {k: jax.device_put(v, shardings[k]) if k in shardings else v
-            for k, v in batch.items()}
+    with trace.span(trace.DATA_PUT):
+        return {k: jax.device_put(v, shardings[k]) if k in shardings else v
+                for k, v in batch.items()}

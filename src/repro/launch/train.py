@@ -124,6 +124,11 @@ def main(argv=None):
                     help="seconds the drift-triggered re-search may "
                          "spend before returning the incumbent")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--trace-dir", default="",
+                    help="write a profiler trace of steps 1 to the end "
+                         "(step 0 compiles) under DIR: device ops carry the "
+                         "train step's scopes, the host the data layer's "
+                         "spans (repro.core.trace)")
     ap.add_argument("--auto-plan", action="store_true",
                     help="let the BaPipe explorer pick stages/tensor/M")
     ap.add_argument("--auto-plan3d", action="store_true",
@@ -295,8 +300,19 @@ def main(argv=None):
             with open(args.losses_out, "w") as f:
                 json.dump(dict(start=start_step, losses=losses), f)
 
-    losses, step_s = [], []
+    def _end_trace():
+        if tracing:
+            jax.profiler.stop_trace()
+            print(f"trace of steps {start_step + 1} to {step} written "
+                  f"under {args.trace_dir}", flush=True)
+
+    losses, step_s, tracing = [], [], False
     for step in range(start_step, args.steps):
+        if args.trace_dir and step == start_step + 1:
+            jax.profiler.start_trace(args.trace_dir)
+            tracing = True
+        # a step's time is its batch build, the step and the loss read
+        t0 = time.perf_counter()
         batch = shard_batch(data.batch(step), bspec)
         if cfg.family == "audio":
             batch["frames"] = jnp.zeros((args.batch, 64, cfg.d_model))
@@ -304,7 +320,6 @@ def main(argv=None):
             batch["pos3"] = jnp.broadcast_to(
                 jnp.arange(args.seq)[None, None],
                 (3, args.batch, args.seq)).astype(jnp.int32)
-        t0 = time.perf_counter()
         params, opt_state, metrics = step_fn(params, opt_state, batch)
         losses.append(float(metrics["loss"]))   # waits for the step
         step_s.append(time.perf_counter() - t0)
@@ -349,10 +364,12 @@ def main(argv=None):
                 and (step + 1) % args.ckpt_every == 0:
             _save(args.ckpt, step + 1, params, opt_state)
         if args.die_at and step + 1 >= args.die_at:
+            _end_trace()
             _dump_losses()
             print(f"fault injection: dying after step {step + 1}",
                   flush=True)
             raise SystemExit(17)
+    _end_trace()
     n = min(10, max(1, len(losses)))
     print(f"first-{n} mean loss {sum(losses[:n])/n:.4f} -> "
           f"last-{n} mean loss {sum(losses[-n:])/n:.4f}")

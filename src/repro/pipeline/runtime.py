@@ -83,6 +83,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core import schedplan as SP
+from repro.core import trace as TR
 from repro.models import layers as LYR
 from repro.models import model as M
 from repro.pipeline import stage as ST
@@ -445,19 +446,28 @@ def _ring_ingest(tab: dict, MV: int, S: int, stage_idx, t, inj, x_cur,
 # Training step factory.
 # ---------------------------------------------------------------------------
 
-def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
-                    pcfg: PipelineConfig, *, optimizer=None,
-                    param_dtype=jnp.float32):
-    """Build the jitted pipeline train step.
+@dataclasses.dataclass(frozen=True)
+class _TrainSchedule:
+    """The train step's compiled schedule: the op table, its tick lowering
+    (and instruction streams under ``runtime='stream'``), and the layer
+    leaves the in-scan gradient sync covers."""
+    specs: Any
+    plan_ir: SP.SchedPlan
+    lowering: SP.TickLowering
+    instr: Optional[SP.InstrLowering]
+    ar_mask: Any
+    overlap_sync: bool
 
-    Returns (step_fn, specs): without an optimizer ``step_fn(params, batch)
-    -> (loss, grads)``; with one ``step_fn(params, opt_state, batch) ->
-    (params, opt_state, metrics)``."""
+
+def _train_schedule(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
+                    pcfg: PipelineConfig, param_dtype) -> _TrainSchedule:
+    """Validate ``pcfg`` and lower the schedule the train step runs; no
+    compile.  :func:`make_train_step` executes it and
+    :func:`train_step_counters` counts it."""
     shape_params = jax.eval_shape(
         lambda k: ST.init_stacked_params(cfg, k, plan, param_dtype),
         jax.ShapeDtypeStruct((2,), jnp.uint32))
     mesh_axes = tuple(mesh.axis_names)
-    batch_axes = _batch_axes(mesh, pcfg)
     stage_ax = _stage_axes(mesh, pcfg)
     S = _n_stages(mesh, pcfg)
     assert plan.n_stages == S, \
@@ -480,10 +490,6 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
     if pcfg.grad_sync == "overlap" and pcfg.runtime != "stream":
         raise ValueError("grad_sync='overlap' requires runtime='stream' "
                          "(the tick replay has no AR slots)")
-    two_bw = pcfg.grad_sync == "2bw"
-    if two_bw and optimizer is None:
-        raise ValueError("grad_sync='2bw' double-buffers the weight "
-                         "update and needs an optimizer")
     if pcfg.ar_groups < 1:
         raise ValueError(f"ar_groups must be >= 1, got {pcfg.ar_groups}")
     if pcfg.ar_groups > 1 and not (
@@ -512,13 +518,79 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
     instr = (SP.lower_to_instructions(plan_ir)
              if pcfg.runtime == "stream" else None)
     lowering = instr.ticks if instr else SP.lower_to_ticks(plan_ir)
-    has_w = lowering.has_w
     if pcfg.remat not in ("none", "stage", "stage_save_moe", "full"):
         raise ValueError(
             f"unknown remat {pcfg.remat!r}: expected none | stage | "
             f"stage_save_moe | full (the first three are equivalent under "
             f"first-class backward ticks — B recomputes the stage from "
             f"its stashed input)")
+    return _TrainSchedule(specs=specs, plan_ir=plan_ir, lowering=lowering,
+                          instr=instr, ar_mask=ar_mask,
+                          overlap_sync=overlap_sync)
+
+
+def train_step_counters(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
+                        pcfg: PipelineConfig, batch: dict, *,
+                        param_dtype=jnp.float32) -> dict:
+    """Static counts of one :func:`make_train_step` step, from the same
+    schedule and lowering, with no compile.  ``batch`` holds the step's
+    global batch (arrays or ``ShapeDtypeStruct``s).
+
+    ``ticks``: slots of the tick scan.  ``send_bytes_per_step``: bytes one
+    chip puts on the forward and backward rings in one step — under
+    ``runtime='ticks'`` both rings shift every tick, under ``'stream'``
+    only at the slots some device sends on them; one stage sends nothing."""
+    ts = _train_schedule(cfg, mesh, plan, pcfg, param_dtype)
+    n_ticks = ts.lowering.n_ticks
+    if _n_stages(mesh, pcfg) == 1:
+        shifts = 0
+    elif ts.instr is not None:
+        shifts = sum(ts.instr.fsend) + sum(ts.instr.bsend)
+    else:
+        shifts = 2 * n_ticks
+    # one ring element: a micro-batch's hidden state [mb, T, d] (and the
+    # encoder's [mb, Sf, d] for audio) in the embedding's dtype
+    B, T = batch["labels"].shape[:2]
+    mb = B // math.prod(mesh.shape[a] for a in _batch_axes(mesh, pcfg)) \
+        // pcfg.n_microbatches
+    rows = T + (batch["frames"].shape[1] if cfg.family == "audio" else 0)
+    payload = mb * rows * cfg.d_model * jnp.dtype(param_dtype).itemsize
+    return dict(ticks=n_ticks, send_bytes_per_step=shifts * payload)
+
+
+def _scoped(name: str, fn):
+    """``fn`` with its ops under device scope ``name``."""
+    def run(*a):
+        with jax.named_scope(name):
+            return fn(*a)
+    return run
+
+
+def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
+                    pcfg: PipelineConfig, *, optimizer=None,
+                    param_dtype=jnp.float32):
+    """Build the jitted pipeline train step.
+
+    Returns (step_fn, specs): without an optimizer ``step_fn(params, batch)
+    -> (loss, grads)``; with one ``step_fn(params, opt_state, batch) ->
+    (params, opt_state, metrics)``.  Its ops carry the device scopes of
+    :mod:`repro.core.trace`."""
+    ts = _train_schedule(cfg, mesh, plan, pcfg, param_dtype)
+    specs, plan_ir, lowering, instr = (ts.specs, ts.plan_ir, ts.lowering,
+                                       ts.instr)
+    ar_mask, overlap_sync = ts.ar_mask, ts.overlap_sync
+    mesh_axes = tuple(mesh.axis_names)
+    batch_axes = _batch_axes(mesh, pcfg)
+    stage_ax = _stage_axes(mesh, pcfg)
+    S = _n_stages(mesh, pcfg)
+    V = plan.virtual
+    M_ = pcfg.n_microbatches
+    two_bw = pcfg.grad_sync == "2bw"
+    if two_bw and optimizer is None:
+        raise ValueError("grad_sync='2bw' double-buffers the weight "
+                         "update and needs an optimizer")
+    dp_size = mesh.shape.get("data", 1)
+    has_w = lowering.has_w
     fsdp_dims = ST.fsdp_scan_dims(specs, virtual=V) if cfg.fsdp else {}
     ep_dp_axis = "data" if (cfg.moe and cfg.moe.ep_data) else None
     ep_n_dp = mesh.shape["data"] if ep_dp_axis else 1
@@ -552,6 +624,7 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
         # micro-batch preparation under vjp: the injection cotangents the
         # scan accumulates (stage 0's chunk-0 B ticks) drive the
         # embedding backward after the scan
+        @functools.partial(_scoped, TR.PIPE_EMBED)
         def prep(embed):
             inj, pos, pos3, mb, T = _prepare_microbatches(
                 cfg, dict(params, embed=embed), batch, M_, tp_index)
@@ -584,6 +657,7 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
                 unroll=pcfg.inner_unroll)
             return y, a
 
+        @functools.partial(_scoped, TR.PIPE_HEAD)
         def head_loss(fn_param, head_param, y, m):
             """Per-micro-batch loss head (final norm + logits + xent):
             seeds the backward of the last virtual stage."""
@@ -735,9 +809,11 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
                 (dlp,) = vjp((ctan, ct_scale))
                 return dict(c, dlp=acc_dlp(c["dlp"], dlp))
 
-            branches = [idle_fn, f_fn, b_ring_fn, b_seed_fn]
+            branches = [idle_fn, _scoped(TR.PIPE_F, f_fn),
+                        _scoped(TR.PIPE_B, b_ring_fn),
+                        _scoped(TR.PIPE_B, b_seed_fn)]
             if has_w:
-                branches.append(w_fn)
+                branches.append(_scoped(TR.PIPE_W, w_fn))
             kind_t = g("kind")
             if plan_ir.has_grad_sync:
                 # AR slots execute below, outside the switch; the
@@ -825,32 +901,35 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
                         treedef, [out[i]
                                   for i in range(len(dlp_leaves))]))
 
-                carry = lax.cond(_at(tab["aron"], t), ar_fn,
-                                 lambda c: c, carry)
+                with jax.named_scope(TR.PIPE_AR):
+                    carry = lax.cond(_at(tab["aron"], t), ar_fn,
+                                     lambda c: c, carry)
             perm_f = [(i, (i + 1) % S) for i in range(S)]
             perm_b = [(i, (i - 1) % S) for i in range(S)]
             shift_f = lambda tr: jax.tree.map(
                 lambda a: lax.ppermute(a, stage_ax, perm_f), tr)
             shift_b = lambda tr: jax.tree.map(
                 lambda a: lax.ppermute(a, stage_ax, perm_b), tr)
-            if instr is not None:
-                # stream runtime: a ring shifts ONLY at slots where some
-                # device SENDs on it.  Every value travels exactly one hop
-                # at its producer's slot (arrival is always the next slot
-                # in the compiled tables), so slots without a scheduled
-                # SEND carry only dead data — skipping the collective is
-                # exact, and W/idle slots run with no barrier at all.
-                # The gate is a function of the slot counter alone
-                # (uniform across devices), so the collective inside the
-                # cond is safe (cf. the gate_ticks serve path).
-                fwd = lax.cond(_at(tab["fsend"], t), shift_f,
-                               lambda tr: tr, carry["fwd"])
-                bwd = lax.cond(_at(tab["bsend"], t), shift_b,
-                               lambda tr: tr, carry["bwd"])
-            else:
-                # tick runtime: both rings shift every tick
-                fwd = shift_f(carry["fwd"])
-                bwd = shift_b(carry["bwd"])
+            with jax.named_scope(TR.PIPE_SEND):
+                if instr is not None:
+                    # stream runtime: a ring shifts ONLY at slots where
+                    # some device SENDs on it.  Every value travels
+                    # exactly one hop at its producer's slot (arrival is
+                    # always the next slot in the compiled tables), so
+                    # slots without a scheduled SEND carry only dead data
+                    # — skipping the collective is exact, and W/idle
+                    # slots run with no barrier at all.  The gate is a
+                    # function of the slot counter alone (uniform across
+                    # devices), so the collective inside the cond is safe
+                    # (cf. the gate_ticks serve path).
+                    fwd = lax.cond(_at(tab["fsend"], t), shift_f,
+                                   lambda tr: tr, carry["fwd"])
+                    bwd = lax.cond(_at(tab["bsend"], t), shift_b,
+                                   lambda tr: tr, carry["bwd"])
+                else:
+                    # tick runtime: both rings shift every tick
+                    fwd = shift_f(carry["fwd"])
+                    bwd = shift_b(carry["bwd"])
             return dict(carry, fwd=fwd, bwd=bwd), None
 
         out, _ = lax.scan(tick, carry0, jnp.arange(nT),
@@ -859,7 +938,8 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
         # ce/aux accumulated only where the table placed the ops, so no
         # stage masking is needed; tensor replication is divided out.
         local = (out["ce"] + out["aux"]) / M_ / (n_batch_shards * tp_size)
-        (d_embed,) = prep_vjp(out["dinj"])
+        with jax.named_scope(TR.PIPE_EMBED):
+            (d_embed,) = prep_vjp(out["dinj"])
         grads = dict(embed=d_embed,
                      layers=jax.tree.map(lambda a: a[None], out["dlp"]),
                      final_norm=out["dfn"])
@@ -871,7 +951,6 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
 
     def sharded_step(params, batch):
         local, grads = global_loss_and_grads(params, batch)
-        loss = lax.psum(local, mesh_axes)
 
         def sync(g, s, layer):
             axes = ST.grad_sync_axes(s, mesh_axes)
@@ -888,10 +967,13 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
                 axes = tuple(a for a in axes if a != "data")
             return lax.psum(g, axes) if axes else g
 
-        grads = {
-            k: jax.tree.map(functools.partial(sync, layer=(k == "layers")),
-                            grads[k], specs[k])
-            for k in grads}
+        with jax.named_scope(TR.PIPE_SYNC):
+            loss = lax.psum(local, mesh_axes)
+            grads = {
+                k: jax.tree.map(
+                    functools.partial(sync, layer=(k == "layers")),
+                    grads[k], specs[k])
+                for k in grads}
         return loss, grads
 
     _built: dict = {}
@@ -911,7 +993,7 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
     opt_update = optimizer.make_update(specs, mesh)
 
     if two_bw:
-        def full_step(params, opt_state, batch):
+        def train_step(params, opt_state, batch):
             # PipeDream-2BW double-buffered weights: compute step k's
             # grads as usual, but APPLY the stashed step k-1 grads —
             # the pending collective result isn't consumed until the
@@ -924,18 +1006,24 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
             apply_g = jax.tree.map(
                 lambda p, g: jnp.where(primed, p, g),
                 opt_state["pending"], grads)
-            params, inner = opt_update(params, apply_g,
-                                       opt_state["inner"])
+            with jax.named_scope(TR.OPT_UPDATE):
+                params, inner = opt_update(params, apply_g,
+                                           opt_state["inner"])
             new_state = dict(inner=inner, pending=grads,
                              primed=jnp.ones((), jnp.bool_))
             return params, new_state, dict(loss=loss)
     else:
-        def full_step(params, opt_state, batch):
+        def train_step(params, opt_state, batch):
             loss, grads = fn(params, batch)
-            params, opt_state = opt_update(params, grads, opt_state)
+            with jax.named_scope(TR.OPT_UPDATE):
+                params, opt_state = opt_update(params, grads, opt_state)
             return params, opt_state, dict(loss=loss)
 
-    return jax.jit(full_step, donate_argnums=(0, 1)), specs
+    # JAX's persistent compilation cache leaves op metadata, the scopes
+    # among it, out of its key, and an executable it loads keeps the
+    # metadata it was compiled with; the step's name (its HLO module's)
+    # is in the key, so a change to the scopes alone renames the step
+    return jax.jit(train_step, donate_argnums=(0, 1)), specs
 
 
 def init_2bw_state(opt_state, params):

@@ -800,6 +800,25 @@ def gated_serve(arch):
     print(f"OK maxerr={max(errs):.2e}")
 
 
+def scoped_hlo(out_path, arch="qwen3-1.7b", stages=4, microbatches=4):
+    """Compile the 1F1B ticks train step with AdamW on ``stages`` devices,
+    write its HLO text to ``out_path`` and print its static counters."""
+    import json
+    from repro.optim import AdamW
+    cfg, plan, params = _setup(arch, stages, 1)
+    mesh = _mesh(1, stages, 1)
+    pcfg = RT.PipelineConfig(n_microbatches=microbatches, schedule="1f1b",
+                             runtime="ticks")
+    opt = AdamW(lr=1e-3)
+    step, _ = RT.make_train_step(cfg, mesh, plan, pcfg, optimizer=opt)
+    batch = _batch(cfg, microbatches, 16)
+    txt = step.lower(params, opt.init(params), batch).compile().as_text()
+    with open(out_path, "w") as f:
+        f.write(txt)
+    counters = RT.train_step_counters(cfg, mesh, plan, pcfg, batch)
+    print("OK " + json.dumps(dict(counters, vocab=cfg.vocab)))
+
+
 if __name__ == "__main__":
     mode = sys.argv[1]
     args = [int(a) if a.lstrip("-").isdigit() else a for a in sys.argv[2:]]
@@ -823,4 +842,5 @@ if __name__ == "__main__":
      "serve_continuous": serve_continuous,
      "elastic_resume": elastic_resume,
      "elastic_drift": elastic_drift,
+     "scoped_hlo": scoped_hlo,
      }[mode](*args)
