@@ -1082,9 +1082,11 @@ class TickLowering:
     * ``kind``  — TICK_IDLE / TICK_F / TICK_B / TICK_B_SEED / TICK_W /
       TICK_AR.  TICK_B_SEED sits on the last virtual stage: its
       cotangent is seeded by the per-micro-batch loss head, not the
-      ring.  TICK_AR (grad-sync plans only) marks the tick a device's
-      chunk-``v`` gradient bucket crosses the data-axis fabric; it is
-      not a compute branch — ``m`` is 0 and ``v`` is the bucket.
+      ring (computed in the tick with one device, read from the split
+      head's dy stash with more).  TICK_AR (grad-sync plans only) marks
+      the tick a device's chunk-``v`` gradient bucket crosses the
+      data-axis fabric; it is not a compute branch — ``m`` is 0 and
+      ``v`` is the bucket.
     * ``m`` / ``v`` — micro-batch and chunk of the tick's op.
     * ``xw`` — residual-stash slot an F writes its stage input to.
     * ``xr`` — residual-stash slot a B/W reads (released by the last
@@ -1103,6 +1105,22 @@ class TickLowering:
     * ``dinj`` — True where a B's input-cotangent is the gradient of the
       fresh injection (virtual stage 0): written to the d_inj buffer for
       the embedding backward instead of the ring.
+    * ``dyr`` — dy-stash slot a TICK_B_SEED reads its cotangent from
+      when the loss head is split over the stages (below).
+
+    The split loss head: with more than one device, every device runs a
+    1/N vocabulary share of micro-batch ``m``'s loss head at the tick the
+    last device finishes F of the last virtual stage, and the last device
+    stashes the head's y-cotangent until that micro-batch's TICK_B_SEED.
+    These tables are per tick only (each ``[n_ticks]``; identical on
+    every device, so the head's collectives run uniformly):
+
+    * ``head`` — True at the tick of the last virtual stage's F.
+    * ``head_m`` — that F's micro-batch (-1 elsewhere).
+    * ``dyw`` — dy-stash slot the head writes (-1 elsewhere).
+
+    ``n_dy`` is the dy stash's size: the most y-cotangents alive at once
+    between a head tick and its B_SEED (1 for 1F1B, M for GPipe).
     """
     schedule: str
     M: int
@@ -1124,10 +1142,15 @@ class TickLowering:
     cw: tuple[tuple[int, ...], ...]
     cr: tuple[tuple[int, ...], ...]
     dinj: tuple[tuple[bool, ...], ...]
+    dyr: tuple[tuple[int, ...], ...]
+    head: tuple[bool, ...]
+    head_m: tuple[int, ...]
+    dyw: tuple[int, ...]
     n_x: int
     n_f: int
     n_b: int
     n_c: int
+    n_dy: int
 
 
 def _assign_ticks(plan: SchedPlan):
@@ -1264,6 +1287,10 @@ def lower_to_ticks(plan: SchedPlan) -> TickLowering:
             s, c = _alloc_slots(cs)
             cslot.update(s)
             n_c = max(n_c, c)
+    # the split head's y-cotangent lives on the last device from the
+    # last virtual stage's F (the head tick) to its B_SEED
+    dyslot, n_dy = _alloc_slots(
+        [(f_tick[(m, NS - 1)], b_tick[(m, NS - 1)], m) for m in range(M)])
 
     # --- table emission -------------------------------------------------
     def tab(fill):
@@ -1283,6 +1310,10 @@ def lower_to_ticks(plan: SchedPlan) -> TickLowering:
     cw = tab(-1)
     cr = tab(-1)
     dinj = tab(False)
+    dyr = tab(-1)
+    head = [False] * n_ticks
+    head_m = [-1] * n_ticks
+    dyw = [-1] * n_ticks
 
     for (m, vs), t in f_tick.items():
         n = dev_of(vs)
@@ -1298,6 +1329,8 @@ def lower_to_ticks(plan: SchedPlan) -> TickLowering:
             fpark[n][f_tick[(m, vs - 1)] + 1] = fslot[(m, vs)]
         else:
             fsrc[n][t] = 1
+        if vs == NS - 1:
+            head[t], head_m[t], dyw[t] = True, m, dyslot[m]
     for (m, vs), t in b_tick.items():
         n = dev_of(vs)
         seed = vs == NS - 1
@@ -1312,6 +1345,8 @@ def lower_to_ticks(plan: SchedPlan) -> TickLowering:
                 bpark[n][b_tick[(m, vs + 1)] + 1] = bslot[(m, vs)]
             else:
                 bsrc[n][t] = 1
+        else:
+            dyr[n][t] = dyslot[m]
         if has_w:
             cw[n][t] = cslot[(m, vs)]
         if vs == 0:
@@ -1335,8 +1370,9 @@ def lower_to_ticks(plan: SchedPlan) -> TickLowering:
         kind=frz(kind), m=frz(m_t), v=frz(v_t), xw=frz(xw), xr=frz(xr),
         fsrc=frz(fsrc), fr=frz(fr), fpark=frz(fpark),
         bsrc=frz(bsrc), br=frz(br), bpark=frz(bpark),
-        cw=frz(cw), cr=frz(cr), dinj=frz(dinj),
-        n_x=n_x, n_f=n_f, n_b=n_b, n_c=n_c)
+        cw=frz(cw), cr=frz(cr), dinj=frz(dinj), dyr=frz(dyr),
+        head=tuple(head), head_m=tuple(head_m), dyw=tuple(dyw),
+        n_x=n_x, n_f=n_f, n_b=n_b, n_c=n_c, n_dy=n_dy)
 
 
 # ---------------------------------------------------------------------------

@@ -266,10 +266,15 @@ def embed_tokens(cfg: ArchConfig, table: jax.Array, tokens: jax.Array,
 
 
 def logits_and_xent(cfg: ArchConfig, params: Params, x: jax.Array,
-                    labels: jax.Array, tp_axis=None, tp_index=None
-                    ) -> jax.Array:
+                    labels: jax.Array, tp_axis=None, tp_index=None, *,
+                    row0=None, skip=None) -> jax.Array:
     """Mean cross-entropy; supports vocab-sharded head via the standard
-    pmax/psum-decomposed softmax (never materialises gathered logits)."""
+    pmax/psum-decomposed softmax (never materialises gathered logits).
+
+    Sharded over ``tp_axis`` (a mesh axis or a tuple of them), the local
+    table holds the global vocabulary rows ``row0 + i`` (default
+    ``tp_index * rows``); its first ``skip`` rows belong to another shard
+    and are masked like the vocabulary pad."""
     table = params.get("head", params["embed"])
     logits = (x @ table.T).astype(jnp.float32)              # [B,T,Vl]
     if tp_axis is None:
@@ -277,15 +282,19 @@ def logits_and_xent(cfg: ArchConfig, params: Params, x: jax.Array,
         lab = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
         return jnp.mean(lse - lab)
     vl = table.shape[0]
-    valid = tp_index * vl + jnp.arange(vl) < cfg.vocab       # mask vocab pad
+    if row0 is None:
+        row0 = tp_index * vl
+    valid = row0 + jnp.arange(vl) < cfg.vocab                # mask vocab pad
+    if skip is not None:
+        valid = valid & (jnp.arange(vl) >= skip)
     logits = jnp.where(valid, logits, -1e30)
     # stop_gradient: the subtracted max is a constant shift (no pmax VJP)
     gmax = lax.pmax(lax.stop_gradient(jnp.max(logits, axis=-1)), tp_axis)
     sumexp = lax.psum(jnp.sum(jnp.exp(logits - gmax[..., None]), axis=-1),
                       tp_axis)
     lse = gmax + jnp.log(sumexp)
-    local = labels - tp_index * vl
-    ok = (local >= 0) & (local < vl)
+    local = labels - row0
+    ok = (local >= (0 if skip is None else skip)) & (local < vl)
     lab = jnp.take_along_axis(logits, jnp.clip(local, 0, vl - 1)[..., None],
                               axis=-1)[..., 0]
     lab = lax.psum(jnp.where(ok, lab, 0.0), tp_axis)

@@ -21,7 +21,14 @@ Execution model (per device, SPMD):
     *backward* ppermute ring (stage s -> s-1), accumulating layer grads
     and sending the input-cotangent upstream.  On the last virtual stage
     the cotangent is seeded by that micro-batch's loss head
-    (final-norm + logits + xent), computed inside the tick;
+    (final-norm + logits + xent).  With one stage the head runs inside
+    the seed tick.  With more, it is split over the stages: at the tick
+    the last stage finishes the micro-batch's forward, every stage runs
+    the head on its ``ceil(vocab / S)`` rows of the (replicated) table,
+    the softmax reduced over the stages, and the head's y-cotangent
+    waits in a register-allocated dy stash for the seed tick, which is
+    then an ordinary ring B — so the last stage no longer holds every
+    lock-step tick for the whole head;
   - a W tick (zero-bubble schedules) re-runs the forward once more and
     applies the stashed cotangent to the *parameters* only — the
     input-gradient B tick earlier propagated the error without paying
@@ -289,7 +296,7 @@ def _tick_tables(lo: SP.TickLowering) -> dict:
     """The tick lowering's per-device per-tick lookup tables as flat
     device constants, indexed by ``stage_idx * n_ticks + t``: op kind,
     micro-batch, chunk, and the stash/inbox slots of the mixed F/B(/W)
-    schedule."""
+    schedule; and the split head's per-tick tables, indexed by ``t``."""
     def flat(rows, dt=jnp.int32):
         return jnp.asarray([x for row in rows for x in row], dt)
     return dict(
@@ -297,7 +304,12 @@ def _tick_tables(lo: SP.TickLowering) -> dict:
         xw=flat(lo.xw), xr=flat(lo.xr),
         fsrc=flat(lo.fsrc), fr=flat(lo.fr), fpark=flat(lo.fpark),
         bsrc=flat(lo.bsrc), br=flat(lo.br), bpark=flat(lo.bpark),
-        cw=flat(lo.cw), cr=flat(lo.cr), dinj=flat(lo.dinj, bool))
+        cw=flat(lo.cw), cr=flat(lo.cr), dinj=flat(lo.dinj, bool),
+        dyr=flat(lo.dyr),
+        # the split head's tables: per tick, the same on every device
+        head=jnp.asarray(lo.head, bool), head_m=jnp.asarray(lo.head_m,
+                                                            jnp.int32),
+        dyw=jnp.asarray(lo.dyw, jnp.int32))
 
 
 def _stream_tables(instr: SP.InstrLowering) -> dict:
@@ -539,10 +551,18 @@ def train_step_counters(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
     ``ticks``: slots of the tick scan.  ``send_bytes_per_step``: bytes one
     chip puts on the forward and backward rings in one step — under
     ``runtime='ticks'`` both rings shift every tick, under ``'stream'``
-    only at the slots some device sends on them; one stage sends nothing."""
+    only at the slots some device sends on them; one stage sends nothing.
+
+    ``head_split``: the chips that share one micro-batch's loss head (its
+    vocabulary split over the stages and the tensor axis).
+    ``head_bytes_per_step``: bytes of the head's collectives one chip
+    takes part in per step — with more than one stage, the psums of the
+    last stage's output and of its cotangent, and with any split the
+    softmax's per-token max, sums and their cotangents."""
     ts = _train_schedule(cfg, mesh, plan, pcfg, param_dtype)
     n_ticks = ts.lowering.n_ticks
-    if _n_stages(mesh, pcfg) == 1:
+    S = _n_stages(mesh, pcfg)
+    if S == 1:
         shifts = 0
     elif ts.instr is not None:
         shifts = sum(ts.instr.fsend) + sum(ts.instr.bsend)
@@ -555,7 +575,16 @@ def train_step_counters(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
         // pcfg.n_microbatches
     rows = T + (batch["frames"].shape[1] if cfg.family == "audio" else 0)
     payload = mb * rows * cfg.d_model * jnp.dtype(param_dtype).itemsize
-    return dict(ticks=n_ticks, send_bytes_per_step=shifts * payload)
+    head_split = S * mesh.shape["tensor"]
+    # one head: the pmax, and the two psums and their two transposes, of
+    # [mb, T] f32; the y and dy psums of the decoder's [mb, T, d]
+    head_bytes = 5 * mb * T * 4 if head_split > 1 else 0
+    if S > 1:
+        head_bytes += 2 * mb * T * cfg.d_model * jnp.dtype(
+            param_dtype).itemsize
+    return dict(ticks=n_ticks, send_bytes_per_step=shifts * payload,
+                head_split=head_split,
+                head_bytes_per_step=pcfg.n_microbatches * head_bytes)
 
 
 def _scoped(name: str, fn):
@@ -574,7 +603,15 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
     Returns (step_fn, specs): without an optimizer ``step_fn(params, batch)
     -> (loss, grads)``; with one ``step_fn(params, opt_state, batch) ->
     (params, opt_state, metrics)``.  Its ops carry the device scopes of
-    :mod:`repro.core.trace`."""
+    :mod:`repro.core.trace`.
+
+    The loss head: with one stage it runs in the seed B tick.  With
+    more than one, every stage runs its share of the vocabulary (split
+    over the stage and tensor axes) in one uniform ``lax.cond`` at the
+    tick the lowering's ``head`` table marks, and the seed B reads its
+    cotangent from the dy stash (:class:`repro.core.schedplan.
+    TickLowering`); each stage accumulates only its rows of the table's
+    gradient."""
     ts = _train_schedule(cfg, mesh, plan, pcfg, param_dtype)
     specs, plan_ir, lowering, instr = (ts.specs, ts.plan_ir, ts.lowering,
                                        ts.instr)
@@ -595,6 +632,10 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
     ep_dp_axis = "data" if (cfg.moe and cfg.moe.ep_data) else None
     ep_n_dp = mesh.shape["data"] if ep_dp_axis else 1
     n_batch_shards = math.prod(mesh.shape[a] for a in batch_axes) or 1
+    # the loss head splits its vocabulary over the stages (and the tensor
+    # axis) wherever there is more than one stage; one stage keeps it in
+    # the seed B tick
+    split_head = S > 1
 
     def batch_spec_for(keys):
         spec = {}
@@ -669,6 +710,30 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
                                       "embed": head_param}, h, labels_m,
                                      "tensor", tp_index)
 
+        if split_head:
+            # this stage's ceil(rows/S) rows of the (tensor-local) table;
+            # the last share starts early enough to fit and masks the
+            # rows its neighbour owns
+            n_rows = head_p.shape[0]
+            share = -(-n_rows // S)
+            own0 = stage_idx * share
+            row_start = jnp.minimum(own0, n_rows - share)
+            head_rows = lax.dynamic_slice_in_dim(head_p, row_start, share, 0)
+            head_axes = ((stage_ax if isinstance(stage_ax, tuple)
+                          else (stage_ax,)) + ("tensor",))
+
+            @functools.partial(_scoped, TR.PIPE_HEAD)
+            def split_xent(h, rows_p, m):
+                """Micro-batch m's cross-entropy over this stage's share
+                of the vocabulary, its softmax reduced over the stages
+                (and the tensor axis)."""
+                labels_m = lax.dynamic_index_in_dim(labels_mb, m, 0,
+                                                    keepdims=False)
+                return M.logits_and_xent(
+                    cfg, {"embed": rows_p}, h, labels_m, head_axes,
+                    row0=tp_index * n_rows + row_start,
+                    skip=own0 - row_start)
+
         zero_pay = jax.tree.map(lambda q: jnp.zeros_like(q[0]), inj)
 
         def buf0(k):
@@ -683,9 +748,15 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
             fin=buf0(lowering.n_f),     # parked forward arrivals
             bin=buf0(lowering.n_b),     # parked backward arrivals
             ct=buf0(lowering.n_c),      # zb: cotangents alive B -> W
+            # split head: y-cotangents alive head tick -> B_SEED
+            dys=(jnp.zeros((lowering.n_dy,) + _hidden_of(zero_pay).shape,
+                           _hidden_of(zero_pay).dtype)
+                 if split_head else None),
             dinj=jax.tree.map(jnp.zeros_like, inj),
             dlp=jax.tree.map(jnp.zeros_like, lp_local),
-            dfn=jnp.zeros_like(fn_p), dhd=jnp.zeros_like(head_p),
+            dfn=jnp.zeros_like(fn_p),
+            # split head: only this stage's rows of the table's gradient
+            dhd=jnp.zeros_like(head_rows if split_head else head_p),
             ce=jnp.zeros((), jnp.float32), aux=jnp.zeros((), jnp.float32))
 
         def tick(carry, t):
@@ -752,9 +823,10 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
                         c["bwd"], inbox)
                 return c["bwd"]
 
-            def b_ring_fn(c):
+            def b_ring_fn(c, ctan=None):
                 x_res = read_res(c)
-                ctan = b_ct(c)
+                if ctan is None:
+                    ctan = b_ct(c)
                 if has_w:
                     # zb: input-gradient only; stash the cotangent for W
                     _, vjp = jax.vjp(
@@ -801,6 +873,39 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
                 return dict(c, bwd=dx, ce=c["ce"] + ce_m,
                             dinj=_buf_write(c["dinj"], m_t, dx, g("dinj")))
 
+            def b_split_seed_fn(c):
+                """The seed B once the head is split: a ring B whose
+                cotangent is the split head's, from the dy stash."""
+                dy = _buf_read(c["dys"], jnp.maximum(g("dyr"), 0))
+                ctan = (dict(zero_pay, h_dec=dy)
+                        if isinstance(zero_pay, dict) else dy)
+                return b_ring_fn(c, ctan)
+
+            def head_fn(c):
+                """The split head on micro-batch ``head_m[t]``, run by
+                every stage: the last stage's output y reaches all by one
+                psum, each takes its share of the vocabulary, and the
+                y-cotangent goes into the dy stash; the loss and the
+                final norm's gradient are counted on the last stage."""
+                m_h = jnp.maximum(_at(tab["head_m"], t), 0)
+                last = stage_idx == S - 1
+                y = _hidden_of(c["fwd"])
+                y = lax.psum(jnp.where(last, y, jnp.zeros_like(y)), stage_ax)
+                h, nvjp = jax.vjp(
+                    lambda yy, fnp: LYR.rms_norm(yy, fnp, cfg.norm_eps),
+                    y, fn_p)
+                ce_m, xvjp = jax.vjp(
+                    lambda hh, rp: split_xent(hh, rp, m_h), h, head_rows)
+                # each stage's copy of the loss carries 1/S of its seed:
+                # the vjp of a psum is a psum over the same stages
+                dh, drows = xvjp(ct_scale / S)
+                dy, dfn_d = nvjp(lax.psum(dh, stage_ax))
+                return dict(
+                    c, dys=_buf_write(c["dys"], _at(tab["dyw"], t), dy, True),
+                    dhd=c["dhd"] + drows,
+                    dfn=c["dfn"] + jnp.where(last, dfn_d, 0.0),
+                    ce=c["ce"] + jnp.where(last, ce_m, 0.0))
+
             def w_fn(c):
                 x_res = read_res(c)
                 ctan = _buf_read(c["ct"], jnp.maximum(g("cr"), 0))
@@ -811,7 +916,8 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
 
             branches = [idle_fn, _scoped(TR.PIPE_F, f_fn),
                         _scoped(TR.PIPE_B, b_ring_fn),
-                        _scoped(TR.PIPE_B, b_seed_fn)]
+                        _scoped(TR.PIPE_B, b_split_seed_fn if split_head
+                                else b_seed_fn)]
             if has_w:
                 branches.append(_scoped(TR.PIPE_W, w_fn))
             kind_t = g("kind")
@@ -822,6 +928,12 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
                                    kind_t)
             carry = lax.switch(jnp.clip(kind_t, 0, len(branches) - 1),
                                branches, carry)
+            if split_head:
+                # the predicate depends on the tick alone, so every
+                # device enters the cond and its collectives line up
+                with jax.named_scope(TR.PIPE_HEAD):
+                    carry = lax.cond(_at(tab["head"], t), head_fn,
+                                     lambda c: c, carry)
             if plan_ir.has_grad_sync:
                 n_groups = plan_ir.grad_sync_groups or 1
 
@@ -943,10 +1055,19 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, plan: ST.StagePlan,
         grads = dict(embed=d_embed,
                      layers=jax.tree.map(lambda a: a[None], out["dlp"]),
                      final_norm=out["dfn"])
+        add_dhd = lambda full: full + out["dhd"]
+        if split_head:
+            # this stage's rows into the full-shape gradient; pipe.sync's
+            # psum over the stages assembles the table's
+            def add_dhd(full):
+                cur = lax.dynamic_slice_in_dim(full, row_start, share, 0)
+                return lax.dynamic_update_slice_in_dim(
+                    full, cur + out["dhd"], row_start, 0)
         if "head" in params:
-            grads["head"] = out["dhd"]
+            grads["head"] = (add_dhd(jnp.zeros_like(head_p)) if split_head
+                             else out["dhd"])
         else:
-            grads["embed"] = grads["embed"] + out["dhd"]
+            grads["embed"] = add_dhd(grads["embed"])
         return local, grads
 
     def sharded_step(params, batch):

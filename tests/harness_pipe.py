@@ -800,12 +800,65 @@ def gated_serve(arch):
     print(f"OK maxerr={max(errs):.2e}")
 
 
+def split_head(cases_json, arch="llama3.2-1b", microbatches=4):
+    """The vocab-split loss head: for each case of ``cases_json`` (a
+    JSON object of name: [stages, tensor, virtual, schedule, runtime,
+    tied]), the pipeline's loss and the gradient of every leaf (the
+    layers, the tied table or embedding and head, the final norm)
+    against the single-device reference.  Prints ``OK`` and a JSON
+    object of per-case errors (relative to each leaf's largest
+    reference entry), or of the error a case raised."""
+    import json
+    out = {}
+    for name, (S, tp, V, sched, runtime, tied) in json.loads(
+            cases_json).items():
+        try:
+            cfg = get_config(arch).reduced(n_layers=S * V, d_model=64)
+            cfg = dataclasses.replace(cfg, stages=S, tensor=tp, virtual=V,
+                                      tie_embeddings=tied)
+            plan = ST.plan_stages(cfg)
+            params = ST.init_stacked_params(cfg, jax.random.PRNGKey(0), plan)
+            # a final norm away from its initial value, so its gradient
+            # is not the one of a constant scale
+            params["final_norm"] = 0.1 * jax.random.normal(
+                jax.random.PRNGKey(1), params["final_norm"].shape)
+            mesh = _mesh(1, S, tp)
+            pcfg = RT.PipelineConfig(n_microbatches=microbatches,
+                                     schedule=sched, runtime=runtime)
+            step, _ = RT.make_train_step(cfg, mesh, plan, pcfg)
+            batch = _batch(cfg, 8, 16)
+            loss, grads = step(params, batch)
+            rp = _ref_params(cfg, params, plan)
+            ref_loss, ref = jax.value_and_grad(
+                lambda p: M.loss_fn(cfg, p, batch))(rp)
+            got = dict(grads, layers=jax.tree.map(
+                lambda a: ST.unstack_chunks(a, plan)[:cfg.n_layers],
+                grads["layers"]))
+
+            def rel(a, b):
+                a, b = np.asarray(a), np.asarray(b)
+                return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+            errs = {k: max(jax.tree.leaves(jax.tree.map(rel, got[k],
+                                                          ref[k])))
+                    for k in ref}
+            out[name] = dict(errs, loss=abs(float(loss) - float(ref_loss)),
+                             leaves=sorted(ref))
+        except Exception:           # reported per case by the test
+            import traceback
+            out[name] = dict(error=traceback.format_exc()[-3000:])
+    print("OK " + json.dumps(out))
+
+
 def scoped_hlo(out_path, arch="qwen3-1.7b", stages=4, microbatches=4):
     """Compile the 1F1B ticks train step with AdamW on ``stages`` devices,
-    write its HLO text to ``out_path`` and print its static counters."""
+    write its HLO text to ``out_path`` and print its static counters.
+    The vocabulary is 1152 rows, so a stage's share of the head (288
+    rows) is no other dimension of the step."""
     import json
     from repro.optim import AdamW
     cfg, plan, params = _setup(arch, stages, 1)
+    cfg = dataclasses.replace(cfg, vocab=1152)
+    params = ST.init_stacked_params(cfg, jax.random.PRNGKey(0), plan)
     mesh = _mesh(1, stages, 1)
     pcfg = RT.PipelineConfig(n_microbatches=microbatches, schedule="1f1b",
                              runtime="ticks")
@@ -816,7 +869,8 @@ def scoped_hlo(out_path, arch="qwen3-1.7b", stages=4, microbatches=4):
     with open(out_path, "w") as f:
         f.write(txt)
     counters = RT.train_step_counters(cfg, mesh, plan, pcfg, batch)
-    print("OK " + json.dumps(dict(counters, vocab=cfg.vocab)))
+    print("OK " + json.dumps(dict(counters, vocab=cfg.vocab,
+                                  microbatches=microbatches)))
 
 
 if __name__ == "__main__":
@@ -843,4 +897,5 @@ if __name__ == "__main__":
      "elastic_resume": elastic_resume,
      "elastic_drift": elastic_drift,
      "scoped_hlo": scoped_hlo,
+     "split_head": split_head,
      }[mode](*args)

@@ -279,3 +279,54 @@ def test_build_schedule_mem_limit_only_for_zb_auto():
         SP.build_schedule("zb-h1", 4, 2, 1, mem_limit=3)
     plan = SP.build_schedule("zb-auto", 4, 2, 1, mem_limit=2)
     assert max(plan.peak_live()) <= 2
+
+
+@pytest.mark.parametrize("name", SP.BUILDER_NAMES)
+@pytest.mark.parametrize("lowering", ["ticks", "stream", "grad_sync"])
+@settings(max_examples=10)
+@given(N=st.integers(1, 6), mmult=st.integers(1, 4), V=st.integers(1, 4))
+def test_split_head_tables(name, lowering, N, mmult, V):
+    """The split head's per-tick tables mark exactly one tick per
+    micro-batch, the last device's F of the last virtual stage; the dy
+    slot written there is the one that micro-batch's B_SEED reads, and
+    the stash holds as many slots as dy values are ever alive at once —
+    for the tick lowering, the instruction streams and a plan with AR
+    ops alike."""
+    M, v = _shape(name, N, mmult, V)
+    plan = SP.build_schedule(name, M, N, v,
+                             grad_sync=lowering == "grad_sync")
+    lo = (SP.lower_to_instructions(plan).ticks if lowering == "stream"
+          else SP.lower_to_ticks(plan))
+    last = N - 1
+    head_ticks = [t for t in range(lo.n_ticks) if lo.head[t]]
+    assert sorted(lo.head_m[t] for t in head_ticks) == list(range(M))
+    alive = [0] * lo.n_ticks
+    for t in head_ticks:
+        m = lo.head_m[t]
+        assert lo.kind[last][t] == SP.TICK_F and lo.m[last][t] == m
+        assert lo.v[last][t] == v - 1
+        seeds = [u for u in range(lo.n_ticks)
+                 if lo.kind[last][u] == SP.TICK_B_SEED
+                 and lo.m[last][u] == m]
+        assert len(seeds) == 1 and seeds[0] > t
+        assert lo.dyr[last][seeds[0]] == lo.dyw[t]
+        for u in range(t, seeds[0] + 1):
+            alive[u] += 1
+    for t in range(lo.n_ticks):
+        if not lo.head[t]:
+            assert lo.head_m[t] == -1 and lo.dyw[t] == -1
+    assert lo.n_dy == max(alive)
+    # only the last device seeds, and only its seeds read the stash
+    for n in range(N):
+        for t in range(lo.n_ticks):
+            seed = lo.kind[n][t] == SP.TICK_B_SEED
+            assert seed <= (n == last)
+            assert (lo.dyr[n][t] >= 0) == seed
+
+
+@pytest.mark.parametrize("name,n_dy", [("1f1b", 1), ("dapple", 1),
+                                       ("zb-h1", 1), ("gpipe", 8)])
+def test_split_head_stash_sizes(name, n_dy):
+    """1F1B's seed B follows its head at once (one dy slot); GPipe runs
+    every forward first (M slots)."""
+    assert SP.lower_to_ticks(SP.build_schedule(name, 8, 4)).n_dy == n_dy

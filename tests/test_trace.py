@@ -7,6 +7,7 @@ The four-stage step compiles in a subprocess on 8 virtual CPU devices
 import dataclasses
 import glob
 import json
+import math
 import os
 import re
 import subprocess
@@ -31,21 +32,30 @@ HARNESS = os.path.join(os.path.dirname(__file__), "harness_pipe.py")
 INSTR = re.compile(r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w-]+)\("
                    r".*?op_name=\"([^\"]*)\"", re.M)
 TRIP = re.compile(r"known_trip_count\":\{\"n\":\"(\d+)\"")
+# an all-reduce, its result one array or a tuple of them, and its op_name
+ALL_REDUCE = re.compile(r"^\s*(?:ROOT )?%\S+ = (\([^)]*\)|\w+\[[\d,]*\]\S*) "
+                        r"all-reduce\(.*?op_name=\"([^\"]*)\"", re.M)
+ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
 ITEMSIZE = dict(f32=4, bf16=2, s32=4, f16=2, u32=4)
 
 
 @pytest.fixture(scope="module")
-def four_stage(tmp_path_factory):
-    """(instructions, trip counts, counters) of the compiled 4-stage
-    1F1B ticks step with AdamW."""
+def four_stage_hlo(tmp_path_factory):
+    """(HLO text, counters) of the compiled 4-stage 1F1B ticks step with
+    AdamW."""
     path = tmp_path_factory.mktemp("hlo") / "step.hlo"
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     r = subprocess.run([sys.executable, HARNESS, "scoped_hlo", str(path)],
                        env=env, capture_output=True, text=True, timeout=420)
     assert r.returncode == 0, r.stderr[-4000:]
-    counters = json.loads(r.stdout.split("OK ", 1)[1])
-    text = path.read_text()
+    return path.read_text(), json.loads(r.stdout.split("OK ", 1)[1])
+
+
+@pytest.fixture(scope="module")
+def four_stage(four_stage_hlo):
+    """(instructions, trip counts, counters) of that step."""
+    text, counters = four_stage_hlo
     instrs = [(dt, tuple(int(d) for d in dims.split(",") if d), op, name)
               for dt, dims, op, name in INSTR.findall(text)]
     return instrs, [int(n) for n in TRIP.findall(text)], counters
@@ -68,12 +78,13 @@ def test_every_ring_send_is_under_pipe_send(four_stage):
 
 
 def test_the_heads_logits_matmuls_are_under_pipe_head(four_stage):
-    """The tied head's logits matmul forward ([mb*T, vocab]) and its
-    weight gradient ([vocab, d]) in the seed B tick."""
+    """The tied head's logits matmul forward ([mb*T, rows]) and its
+    weight gradient ([rows, d]), each stage's share of the vocabulary's
+    rows in the split head."""
     instrs, _, counters = four_stage
-    vocab = counters["vocab"]
+    rows = -(-counters["vocab"] // counters["head_split"])
     dots = [(shape, name) for _, shape, op, name in instrs
-            if op in ("dot", "convolution") and vocab in shape]
+            if op in ("dot", "convolution") and rows in shape]
     assert len(dots) >= 2
     assert {scope_of(n) for _, n in dots} == {TR.PIPE_HEAD}
     assert any("transpose(jvp(pipe.head))" in n for _, n in dots)
@@ -99,6 +110,21 @@ def test_send_bytes_equal_the_compiled_permutes_times_the_trip_count(
     assert counters["send_bytes_per_step"] == per_tick * counters["ticks"]
 
 
+def test_head_bytes_equal_the_compiled_head_reductions_per_micro_batch(
+        four_stage_hlo):
+    """The all-reduces under ``pipe.head`` run once per micro-batch (the
+    head's cond), and move the counted bytes."""
+    text, counters = four_stage_hlo
+    arrays = [ARRAY.findall(result) for result, name
+              in ALL_REDUCE.findall(text) if scope_of(name) == TR.PIPE_HEAD]
+    per_head = sum(ITEMSIZE[dt] * math.prod(int(d) for d in dims.split(",")
+                                            if d)
+                   for reduce in arrays for dt, dims in reduce)
+    assert per_head > 0
+    assert counters["head_bytes_per_step"] == (counters["microbatches"]
+                                               * per_head)
+
+
 def _counters(runtime, stages=4, microbatches=8, batch=8, seq=32):
     cfg = get_config("qwen3-1.7b").reduced(n_layers=4, d_model=64)
     cfg = dataclasses.replace(cfg, stages=stages, tensor=1)
@@ -116,9 +142,8 @@ def test_stream_counts_only_the_scheduled_sends():
     ticks, payload, plan_ir = _counters("ticks")
     stream, _, _ = _counters("stream")
     instr = SP.lower_to_instructions(plan_ir)
-    assert ticks == dict(ticks=instr.ticks.n_ticks,
-                         send_bytes_per_step=2 * instr.ticks.n_ticks
-                         * payload)
+    assert ticks["ticks"] == instr.ticks.n_ticks
+    assert ticks["send_bytes_per_step"] == 2 * instr.ticks.n_ticks * payload
     assert stream["send_bytes_per_step"] == payload * (
         sum(instr.fsend) + sum(instr.bsend))
     assert 0 < stream["send_bytes_per_step"] < ticks["send_bytes_per_step"]
